@@ -20,11 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:        # legacy home of shard_map (jax <= 0.4.x); removed in newer jax
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-except ImportError:
-    _legacy_shard_map = None
-
 _CTX = threading.local()
 
 # Default logical->physical tables. `None` entries mean "replicated".
@@ -108,18 +103,6 @@ def named_sharding(mesh: Mesh, rules: dict, axes) -> NamedSharding:
 # sharded batch decode
 # ---------------------------------------------------------------------------
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map across jax versions: newer jax exposes `jax.shard_map` with
-    `check_vma`; older releases have `jax.experimental.shard_map.shard_map`
-    with `check_rep`. `check=False` everywhere — the decode/MoE bodies use
-    while_loop/collectives patterns the static replication checker rejects."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check)
-
-
 def data_mesh(axis_name: str = "data") -> Mesh:
     """1-D mesh over every visible device, for batch-parallel decode."""
     return Mesh(np.array(jax.devices()), (axis_name,))
@@ -161,11 +144,12 @@ def decode_sharded(code, y, *, mesh: Mesh | None = None,
                                cn_fbp=cn_fbp)
 
     spec = P(axis_name)
-    # check=False: jax<=0.4.x has no replication rule for while_loop
-    # (the early-exit path); outputs are all batch-sharded anyway.
-    y_corr, res = compat_shard_map(
+    # check_vma=False: outputs are all batch-sharded, and the per-device
+    # decode body needs no replication tracking
+    y_corr, res = jax.shard_map(
         local_decode, mesh=mesh, in_specs=spec,
-        out_specs=(spec, DecodeResult(spec, spec, spec, spec)))(y)
+        out_specs=(spec, DecodeResult(spec, spec, spec, spec)),
+        check_vma=False)(y)
     if pad:
         y_corr = y_corr[:B]
         res = DecodeResult(res.symbols[:B], res.llv_totals[:B],
@@ -218,6 +202,6 @@ def scan_syndromes_sharded(code, y, *, mesh: Mesh | None = None,
         return scan_syndromes(y_local, ht, code.p, interpret=interpret)
 
     spec = P(axis_name)
-    flags = compat_shard_map(local_scan, mesh=mesh, in_specs=spec,
-                             out_specs=spec)(y)
+    flags = jax.shard_map(local_scan, mesh=mesh, in_specs=spec,
+                          out_specs=spec, check_vma=False)(y)
     return flags[:B] if pad else flags

@@ -95,13 +95,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
         s = _scores(q_ref[0], k_ref[0], scale, softcap)      # (bq, bk) f32
         ok = _mask(qpos, kpos, causal, window, kv_len)
         s = jnp.where(ok, s, NEG_INF)
-        m_new = jnp.maximum(m[...], s.max(axis=-1))
+        m_new = jnp.maximum(m[...], s.max(axis=-1, keepdims=True))
         # guard fully-masked rows (exp(NEG_INF - NEG_INF) = 1 otherwise)
         alive = m_new > NEG_INF / 2
-        p = jnp.where(alive[:, None], jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
         c = jnp.where(alive, jnp.exp(m[...] - m_new), 1.0)
-        l[...] = l[...] * c + p.sum(axis=-1)
-        acc[...] = acc[...] * c[:, None] + jax.lax.dot_general(
+        l[...] = l[...] * c + p.sum(axis=-1, keepdims=True)
+        acc[...] = acc[...] * c + jax.lax.dot_general(
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m[...] = m_new
@@ -109,8 +109,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
     @pl.when(j == nkv - 1)
     def _fin():
         safe_l = jnp.maximum(l[...], 1e-30)
-        o_ref[0] = (acc[...] / safe_l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m[...] + jnp.log(safe_l)
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
+        lse_ref[0] = (m[...] + jnp.log(safe_l)).T          # (1, bq) row
 
 
 def flash_fwd(q, k, v, *, g: int, scale: float, causal: bool, window: int,
@@ -132,7 +132,7 @@ def flash_fwd(q, k, v, *, g: int, scale: float, causal: bool, window: int,
                              window=window, softcap=softcap, bq=bq, bk=bk,
                              nkv=nkv, kv_len=kv_len)
     kv_map = lambda b, i, j: (b // g, j, 0)
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kern,
         grid=(BH, nq, nkv),
         in_specs=[
@@ -142,19 +142,21 @@ def flash_fwd(q, k, v, *, g: int, scale: float, causal: bool, window: int,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
+    return o, lse[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -177,12 +179,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     s = _scores(q_ref[0], k_ref[0], scale, softcap)
     ok = _mask(qpos, kpos, causal, window, kv_len)
-    p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, None]), 0.0)     # (bq, bk)
+    p = jnp.where(ok, jnp.exp(s - lse_ref[0].T), 0.0)            # (bq, bk)
     dp = jax.lax.dot_general(do_ref[0].astype(jnp.float32),
                              v_ref[0].astype(jnp.float32),
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])                        # dL/ds
+    ds = p * (dp - delta_ref[0].T)                               # dL/ds
     if softcap:
         ds = ds * (1.0 - (s / softcap) ** 2)
     ds = ds * scale
@@ -211,7 +213,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     s = _scores(q_ref[0], k_ref[0], scale, softcap)
     ok = _mask(qpos, kpos, causal, window, kv_len)
-    p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, None]), 0.0)     # (bq, bk)
+    p = jnp.where(ok, jnp.exp(s - lse_ref[0].T), 0.0)            # (bq, bk)
     accv[...] += jax.lax.dot_general(p, do_ref[0].astype(jnp.float32),
                                      (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -219,7 +221,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              v_ref[0].astype(jnp.float32),
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0].T)
     if softcap:
         ds = ds * (1.0 - (s / softcap) ** 2)
     ds = ds * scale
@@ -246,6 +248,8 @@ def flash_bwd(q, k, v, o, lse, do, *, g: int, scale: float, causal: bool,
     bk = min(bk, Skv)
     nq, nkv = Sq // bq, Skv // bk
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)  # (BH,Sq)
+    # (BH, 1, Sq): per-row statistics ride lane-dense (1, bq) blocks
+    lse, delta = lse[:, None], delta[:, None]
 
     kv_map = lambda b, i, j: (b // g, j, 0)
     dq = pl.pallas_call(
@@ -258,12 +262,13 @@ def flash_bwd(q, k, v, o, lse, do, *, g: int, scale: float, causal: bool,
             pl.BlockSpec((1, bk, D), kv_map),                      # k
             pl.BlockSpec((1, bk, D), kv_map),                      # v
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),         # lse
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),         # delta
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # lse
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # delta
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -278,8 +283,8 @@ def flash_bwd(q, k, v, o, lse, do, *, g: int, scale: float, causal: bool,
             pl.BlockSpec((1, bk, D), kv_map2),                     # k
             pl.BlockSpec((1, bk, D), kv_map2),                     # v
             pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),         # lse
-            pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),         # delta
+            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i)),   # lse
+            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i)),   # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
@@ -291,6 +296,7 @@ def flash_bwd(q, k, v, o, lse, do, *, g: int, scale: float, causal: bool,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

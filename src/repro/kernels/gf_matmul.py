@@ -12,8 +12,10 @@ K-blocked MXU accumulation, but the mod-p + nonzero-any reduction over the
 check dimension is fused into the last K-step, so only a (B,) flagged mask
 leaves the kernel — the full syndrome matrix never exists outside VMEM.
 
-Accumulation is exact int32; inputs are small integers (field symbols or
-centered lifts), far from overflow for K ≤ 2^20.
+Operands reach the MXU as int8 (Mosaic has no int32 x int32 matmul) and
+accumulate in int32: field symbols and stored levels fit int8 for p <= 127,
+and the callers in `ops` cast and guard that, plus the int32 accumulator
+bound K*(p-1)^2 < 2^31.
 """
 from __future__ import annotations
 
@@ -38,10 +40,9 @@ def _gf_matmul_kernel(a_ref, b_ref, o_ref, *, p: int, nk: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
     o_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -51,7 +52,7 @@ def _gf_matmul_kernel(a_ref, b_ref, o_ref, *, p: int, nk: int):
 def gf_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, p: int, *,
                      bm: int = 128, bn: int = 128, bk: int = 128,
                      interpret: bool | None = None) -> jnp.ndarray:
-    """(a @ b) % p. a: (M, K) int, b: (K, N) int -> (M, N) int32.
+    """(a @ b) % p. a: (M, K) int8, b: (K, N) int8 -> (M, N) int32.
 
     The output block is revisited across the K grid dimension (accumulate in
     VMEM, mod-p epilogue on the last step). Caller (`ops.gf_matmul`) pads
@@ -72,6 +73,7 @@ def gf_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, p: int, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         grid=(M // bm, N // bn, nk),
+        name="gf_matmul",
         interpret=resolve_interpret(interpret),
     )(a, b)
 
@@ -83,10 +85,9 @@ def _scan_syndromes_kernel(y_ref, ht_ref, o_ref, acc_ref, *, p: int, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    y = y_ref[...].astype(jnp.int32)
-    ht = ht_ref[...].astype(jnp.int32)
     acc_ref[...] += jax.lax.dot_general(
-        y, ht, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        y_ref[...], ht_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _flag():
@@ -100,7 +101,8 @@ def scan_syndromes_pallas(y: jnp.ndarray, ht: jnp.ndarray, p: int, *,
                           interpret: bool | None = None) -> jnp.ndarray:
     """Fused scrub scan: flags[i] = any((y[i] @ ht) % p != 0).
 
-    y: (M, K) stored level-words, ht: (K, C) check matrix transpose ->
+    y: (M, K) int8 stored level-words, ht: (K, C) int8 check matrix
+    transpose ->
     (M, FLAG_LANES) int32 with the per-word flag broadcast across lanes
     (callers read column 0). The (bm, C) syndrome accumulator lives in VMEM
     scratch and is reduced in the last K-step — the syndrome matrix is never
@@ -123,5 +125,6 @@ def scan_syndromes_pallas(y: jnp.ndarray, ht: jnp.ndarray, p: int, *,
         out_specs=pl.BlockSpec((bm, FLAG_LANES), lambda i, k: (i, 0)),
         scratch_shapes=[pltpu.VMEM((bm, C), jnp.int32)],
         grid=(M // bm, nk),
+        name="scan_syndromes",
         interpret=resolve_interpret(interpret),
     )(y, ht)

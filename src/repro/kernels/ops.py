@@ -34,6 +34,18 @@ from repro.analysis.sanitizer import (check_finite, check_gf_symbols,
 from repro.core.llv import NEG_INF
 
 
+# The GF matmul kernels feed the MXU int8 operands (Mosaic has no int32 x
+# int32 matmul), so symbols and stored levels must fit int8: p <= 127.
+# Larger fields belong on the exact host scan (MemoryController).
+MAX_KERNEL_FIELD = 127
+
+
+def _check_int8_field(p: int, what: str) -> None:
+    if p > MAX_KERNEL_FIELD:
+        raise ValueError(f"{what}: GF({p}) symbols do not fit the kernel's "
+                         f"int8 operands (p <= {MAX_KERNEL_FIELD})")
+
+
 def _pad_to(x, axis, multiple, value=0):
     pad = (-x.shape[axis]) % multiple
     if pad == 0:
@@ -46,12 +58,13 @@ def _pad_to(x, axis, multiple, value=0):
 @functools.partial(jax.jit, static_argnames=("p", "tile_n", "interpret"))
 def _fbp_cn_jit(m_hat: jnp.ndarray, p: int, tile_n: int,
                 interpret: bool) -> jnp.ndarray:
-    N = m_hat.shape[0]
-    # pick the tile first, then derive the pad FROM the chosen tile so the
-    # padded batch is a tile multiple by construction (asserted below; the
-    # 8-row floor matches the float32 sublane minimum, so a smaller explicit
-    # tile_n is rounded up rather than honored)
-    tile = max(8, min(tile_n, N))
+    N, dc, _ = m_hat.shape
+    # the kernel folds the batch onto (8, 128) f32 vreg tiles: round the
+    # tile to whole vregs and to no more than the (rounded) batch, then pad
+    # the batch FROM the chosen tile so it is a tile multiple by
+    # construction (asserted below)
+    vreg = _fbp.SUBLANES * _fbp.LANES
+    tile = min(-(-tile_n // vreg), -(-N // vreg)) * vreg
     padded, pad = _pad_to(m_hat, 0, tile)
     assert padded.shape[0] % tile == 0, (
         f"padded N={padded.shape[0]} not divisible by tile={tile}")
@@ -59,8 +72,9 @@ def _fbp_cn_jit(m_hat: jnp.ndarray, p: int, tile_n: int,
         fill = jnp.full((pad,) + m_hat.shape[1:], NEG_INF, m_hat.dtype)
         fill = fill.at[..., 0].set(0.0)
         padded = padded.at[N:].set(fill)
-    out = _fbp.fbp_cn_pallas(padded, p, tile_n=tile, interpret=interpret)
-    return out[:N]
+    slots = padded.transpose(1, 2, 0).reshape(dc, p, -1, _fbp.LANES)
+    out = _fbp.fbp_cn_pallas(slots, p, tile_n=tile, interpret=interpret)
+    return out.reshape(dc, p, -1).transpose(2, 0, 1)[:N]
 
 
 def fbp_cn(m_hat: jnp.ndarray, p: int, *, tile_n: int = _fbp.DEFAULT_TILE_N,
@@ -87,7 +101,9 @@ def _gf_matmul_jit(a: jnp.ndarray, b: jnp.ndarray, p: int, bm: int, bn: int,
     # or the mod-p epilogue sees a wrapped sum and returns garbage
     assert K * (p - 1) ** 2 < 2 ** 31, (
         f"gf_matmul int32 bound exceeded: {K} * ({p}-1)^2 >= 2^31")
+    _check_int8_field(p, "gf_matmul")
     bm_, bn_, bk_ = (min(bm, max(8, M)), min(bn, max(8, N)), min(bk, max(8, K)))
+    a, b = a.astype(jnp.int8), b.astype(jnp.int8)
     a, _ = _pad_to(a, 0, bm_)
     a, _ = _pad_to(a, 1, bk_)
     b, _ = _pad_to(b, 0, bk_)
@@ -134,7 +150,9 @@ def _scan_syndromes_jit(y: jnp.ndarray, ht: jnp.ndarray, p: int, bm: int,
     # controller routes such codes to its exact int64 host path.
     assert K * (p - 1) ** 2 < 2 ** 31, (
         f"scan_syndromes int32 bound exceeded: {K} * ({p}-1)^2 >= 2^31")
+    _check_int8_field(p, "scan_syndromes")
     bm_, bk_ = min(bm, max(8, M)), min(bk, max(8, K))
+    y, ht = y.astype(jnp.int8), ht.astype(jnp.int8)
     y, _ = _pad_to(y, 0, bm_)
     y, _ = _pad_to(y, 1, bk_)
     ht, _ = _pad_to(ht, 0, bk_)
@@ -167,20 +185,34 @@ def _pim_mac_jit(x: jnp.ndarray, w: jnp.ndarray, row_parallelism: int,
     B, K = x.shape
     _, N = w.shape
     R = row_parallelism if row_parallelism > 0 else K
-    bm_, bn_ = min(bm, max(8, B)), min(bn, max(8, N))
-    x, _ = _pad_to(x, 0, bm_)
     x, _ = _pad_to(x, 1, R)           # zero rows contribute clip(0)=0
     w, _ = _pad_to(w, 0, R)
+    # zero-pad each group to a width that tiles the 128-lane K block: a
+    # power of two up to 128 (several groups per block), else a multiple
+    # of 128 (one group per block)
+    Rp = 2 ** math.ceil(math.log2(R)) if R <= 128 else -(-R // 128) * 128
+    gpb = max(1, 128 // Rp)
+    g = x.shape[1] // R
+    x = jnp.pad(x.reshape(B, g, R), ((0, 0), (0, 0), (0, Rp - R)))
+    w = jnp.pad(w.reshape(g, R, N), ((0, 0), (0, Rp - R), (0, 0)))
+    x, w = x.reshape(B, g * Rp), w.reshape(g * Rp, N)
+    x, _ = _pad_to(x, 1, Rp * gpb)    # whole zero groups: exact no-ops
+    w, _ = _pad_to(w, 0, Rp * gpb)
+    bm_, bn_ = min(bm, max(8, B)), min(bn, max(8, N))
+    x, _ = _pad_to(x, 0, bm_)
     w, _ = _pad_to(w, 1, bn_)
-    out = _pm.pim_mac_pallas(x, w, row_parallelism=R, adc_levels=adc_levels,
-                             bm=bm_, bn=bn_, interpret=interpret)
+    out = _pm.pim_mac_pallas(x.astype(jnp.int8), w.astype(jnp.int8),
+                             row_parallelism=Rp, adc_levels=adc_levels,
+                             bm=bm_, bn=bn_, groups_per_block=gpb,
+                             interpret=interpret)
     return out[:B, :N]
 
 
 def pim_mac(x: jnp.ndarray, w: jnp.ndarray, *, row_parallelism: int = 0,
             adc_levels: int = 0, bm: int = 128, bn: int = 128,
             interpret: bool | None = None) -> jnp.ndarray:
-    """Row-group-quantized MAC (B, K) x (K, N) -> (B, N) int32."""
+    """Row-group-quantized MAC (B, K) x (K, N) -> (B, N) int32. Inputs and
+    cell values reach the MXU as int8, so they must lie in [-128, 127]."""
     return _pim_mac_jit(x, w, row_parallelism, adc_levels, bm, bn,
                         _resolve_interpret(interpret))
 

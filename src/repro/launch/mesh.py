@@ -6,26 +6,16 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 SINGLE_POD = (16, 16)                    # 256 chips
 MULTI_POD = (2, 16, 16)                  # 2 pods x 256 = 512 chips
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: `axis_types` (and
-    `jax.sharding.AxisType`) only exist on newer jax; older releases have
-    exactly the Auto behavior, so dropping the argument is equivalent."""
-    try:
-        kinds = (jax.sharding.AxisType.Auto,) * len(shape)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=kinds)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -33,4 +23,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

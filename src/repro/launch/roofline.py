@@ -22,10 +22,23 @@ import os
 
 import numpy as np
 
-# TPU v5e constants (per chip), from the brief
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+# Per-chip peaks keyed by `jax.devices()[0].device_kind`. TPU v5e: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM); the
+# per-link ICI figure is the dry-run's planning number. A kind that is not
+# in the table is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# the dry-run placeholder meshes model TPU v5e pods
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak bf16 FLOP/s, HBM bytes/s and ICI bytes/s per link of one chip."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak table entry for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -61,6 +74,7 @@ def model_flops(cfg, shape, n_active: float) -> float:
 
 
 def analyze_cell(path: str):
+    pk = peaks(DRYRUN_DEVICE_KIND)
     with open(path) as f:
         d = json.load(f)
     if d.get("skipped") or not d.get("ok") or "composed" not in d:
@@ -71,9 +85,9 @@ def analyze_cell(path: str):
     nd = 512 if d["mesh"] == "multi" else 256
 
     comp = d["composed"]
-    t_compute = comp["flops_per_dev"] / PEAK_FLOPS
-    t_memory = comp["bytes_per_dev"] / HBM_BW
-    t_coll = comp["collective_wire_bytes"] / ICI_BW
+    t_compute = comp["flops_per_dev"] / pk["flops"]
+    t_memory = comp["bytes_per_dev"] / pk["hbm_bw"]
+    t_coll = comp["collective_wire_bytes"] / pk["ici_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     bottleneck = max(terms, key=terms.get)
 
@@ -84,7 +98,7 @@ def analyze_cell(path: str):
 
     # roofline fraction: useful model flops per second at the bottleneck
     step_time = max(terms.values())
-    mfu = mf / nd / step_time / PEAK_FLOPS if step_time else 0.0
+    mfu = mf / nd / step_time / pk["flops"] if step_time else 0.0
 
     mem = d["full"]["mem"]
     hbm_gb = (mem["argument_bytes"] + mem["temp_bytes"]

@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import PIMSpec
 from repro.core.context import PIMContext
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decode_step, init_caches, init_params, prefill
 
 
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--fault-rate", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

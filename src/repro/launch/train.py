@@ -28,6 +28,7 @@ from repro.data import DataConfig, TokenPipeline
 from repro.distributed.fault import RestartManager, StragglerWatchdog
 from repro.distributed.sharding import use_rules
 from repro.launch.cells import rules_for_cell, settings_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_train
 from repro.models import init_params
@@ -50,6 +51,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -34,7 +34,7 @@ import numpy as np
 from repro.core import get_code, np_encode_words
 from repro.core.construction import LDPCCode
 
-from .channel import Channel
+from .channel import Channel, apply_faults
 from .controller import MemoryController, make_controller
 from .packing import digits_per_byte, symbolize_bytes, desymbolize_bytes
 
@@ -128,11 +128,13 @@ class ProtectedMemoryArray:
 
     def inject(self, channel: Channel | None = None,
                key: int | jax.Array | None = None, *, t: float = 0.0,
-               n_reads: int = 0) -> int:
+               n_reads: int = 0, exact: int = 0) -> int:
         """Corrupt the stored words in place through a channel model. `key`
         is a PRNG key or a plain int seed. Returns the number of cells
         actually changed. Each call folds a fresh sub-key, so repeated
-        injections accumulate (aging)."""
+        injections accumulate (aging). `exact=m` corrupts exactly m cells
+        of every word (`Channel.corrupt_exact`) instead of sampling the
+        channel per cell — a known error count per word."""
         ch = channel if channel is not None else self.channel
         if ch is None:
             raise ValueError("no channel: pass one or construct the array "
@@ -151,8 +153,9 @@ class ProtectedMemoryArray:
         for i, name in enumerate(self.names):
             st = self._store[name]
             k = jax.random.fold_in(key, i)
-            new = np.asarray(ch.apply(k, jnp.asarray(st.enc, jnp.int32),  # noqa: RPL007 - fault-injection utility, not a hot path; storage is host numpy
-                                      t=t, n_reads=n_reads), np.int8)
+            words = jnp.asarray(st.enc, jnp.int32)
+            new = np.asarray(apply_faults(ch, k, words, exact, t=t,  # noqa: RPL007 - fault-injection utility, not a hot path; storage is host numpy
+                                          n_reads=n_reads), np.int8)
             changed += int((new != st.enc).sum())
             st.enc = new
         return changed

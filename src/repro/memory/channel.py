@@ -36,7 +36,7 @@ import numpy as np
 __all__ = [
     "Channel", "LevelTransition", "RetentionDrift", "ReadDisturb",
     "StuckAt", "Compose", "PlusMinusOne", "uniform_flip",
-    "asymmetric_adjacent", "validate_transition",
+    "asymmetric_adjacent", "validate_transition", "apply_faults",
 ]
 
 
@@ -318,3 +318,13 @@ class PlusMinusOne(Channel):
         cur = jnp.take_along_axis(words, pos, axis=1)
         return words.at[jnp.arange(B)[:, None], pos].set(
             cur + sign.astype(words.dtype))
+
+
+def apply_faults(channel: Channel, key: jax.Array, words: jnp.ndarray,
+                 exact: int, **kw) -> jnp.ndarray:
+    """The fault-injection step shared by every store's `inject`: sample
+    the channel per cell, or with `exact=m` corrupt exactly m cells of
+    every word (row)."""
+    if exact:
+        return channel.corrupt_exact(key, words, exact, **kw)
+    return channel.apply(key, words, **kw)

@@ -274,11 +274,14 @@ class MemoryController:
 
     def _scan_route(self, code: LDPCCode) -> str:
         """The backend a scan of `code` ACTUALLY runs on: the device kernel
-        accumulates in int32, so fields/words beyond its exact bound route
-        to the host scan (whose own fallback is int64) even when the device
-        backend is configured."""
+        takes int8 operands and accumulates in int32, so fields past int8
+        and fields/words beyond its exact bound route to the host scan
+        (whose own fallback is int64) even when the device backend is
+        configured."""
+        from repro.kernels.ops import MAX_KERNEL_FIELD
         if (self.resolved_scan_backend() == "device"
-                and code.n * (code.p - 1) ** 2 < 2 ** 31):
+                and code.n * (code.p - 1) ** 2 < 2 ** 31
+                and code.p <= MAX_KERNEL_FIELD):
             return "device"
         return "host"
 

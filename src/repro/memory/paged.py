@@ -44,7 +44,7 @@ from repro.core.protected import decode_pipelined, np_prod_mesh
 from repro.obs import metrics as obs_metrics
 from repro.obs import ras as obs_ras
 
-from .channel import Channel
+from .channel import Channel, apply_faults
 from .controller import ControllerStats
 from .packing import digits_per_byte, symbolize_u8, desymbolize_u8
 
@@ -134,6 +134,12 @@ class PagedProtectedStore:
                 f"code n={self.code.n} p={self.code.p} exceeds the int32 "
                 "kernel accumulator bound n*(p-1)^2 < 2^31; use "
                 "MemoryController's exact host scan for this code")
+        from repro.kernels.ops import MAX_KERNEL_FIELD
+        if self.code.p > MAX_KERNEL_FIELD:
+            raise ValueError(
+                f"GF({self.code.p}) symbols do not fit the kernels' int8 "
+                f"operands (p <= {MAX_KERNEL_FIELD}); use MemoryController's "
+                "exact host scan for this code")
         # Backend selection is one KernelPolicy (repro.kernels.backend):
         # None defers to the ambient policy at executable-build time —
         # "auto" compiles the Pallas kernels natively on TPU and routes to
@@ -391,11 +397,12 @@ class PagedProtectedStore:
 
     def inject(self, channel: Channel,
                key: int | jax.Array | None = None, *, t: float = 0.0,
-               n_reads: int = 0) -> int:
+               n_reads: int = 0, exact: int = 0) -> int:
         """Corrupt the stored pages in place through a level-domain channel
         model (device-side). Returns the number of cells changed. Pad rows
         of the trailing page are corrupted too — they are storage like any
-        other row, and the scan/decode path treats their errors normally."""
+        other row, and the scan/decode path treats their errors normally.
+        `exact=m` corrupts exactly m cells of every word instead."""
         if channel.domain != "level":
             raise ValueError(f"{type(channel).__name__} is an integer-domain "
                              "channel; stored cells need a level-domain one")
@@ -411,7 +418,7 @@ class PagedProtectedStore:
         for i in range(self.n_pages):
             page = self.page(i)
             k = jax.random.fold_in(key, i)
-            new = channel.apply(k, page, t=t, n_reads=n_reads)
+            new = apply_faults(channel, k, page, exact, t=t, n_reads=n_reads)
             new = new.astype(jnp.int32)
             changed += int(jnp.sum(new != page))
             self._set_page(i, new)

@@ -39,6 +39,7 @@ from repro.core.construction import LDPCCode
 from repro.obs import metrics as obs_metrics
 from repro.obs import ras as obs_ras
 
+from .channel import apply_faults
 from .controller import ControllerStats
 from .paged import PagedProtectedStore
 
@@ -354,11 +355,12 @@ class ProtectedPagePool:
     # -- fault injection over the whole pool --------------------------------
 
     def inject(self, channel, key: int | jax.Array, *, t: float = 0.0,
-               n_reads: int = 0, owners=None) -> int:
+               n_reads: int = 0, owners=None, exact: int = 0) -> int:
         """Corrupt allocated pool pages in place through a level-domain
         channel (optionally only pages owned by `owners`). Returns cells
         changed. Shared pages are corrupted once — exactly like one physical
-        page going bad under every alias."""
+        page going bad under every alias. `exact=m` corrupts exactly m cells
+        of every word instead of sampling the channel per cell."""
         if channel.domain != "level":
             raise ValueError(f"{type(channel).__name__} is an integer-domain "
                              "channel; stored cells need a level-domain one")
@@ -376,7 +378,7 @@ class ProtectedPagePool:
             if want is not None and self._owner[pid] not in want:
                 continue
             k = jax.random.fold_in(key, pid)
-            new = channel.apply(k, page, t=t, n_reads=n_reads)
+            new = apply_faults(channel, k, page, exact, t=t, n_reads=n_reads)
             new = new.astype(jnp.int32)
             changed += int(jnp.sum(new != page))
             self._storage[pid] = new

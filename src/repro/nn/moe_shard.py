@@ -104,9 +104,10 @@ def moe_shard_apply(params, x, cfg: ArchConfig):
     dp_axes = tuple(a for a in mesh.axis_names if a != "model")
 
     fn = functools.partial(_local_moe, cfg=cfg, ep=ep)
-    from repro.distributed.sharding import compat_shard_map
-    mapped = compat_shard_map(
-        fn, mesh=mesh,
+    # check_vma=False: the expert body's all_to_all pattern is rejected by
+    # the static replication checker
+    mapped = jax.shard_map(
+        fn, mesh=mesh, check_vma=False,
         in_specs=(P(dp_axes, None),                # x2d: tokens over DP axes
                   P(),                             # router replicated
                   P("model", None, None),          # experts over model

@@ -533,6 +533,9 @@ class ServingEngine:
         self.sequences: list[SequenceState] = []
         self._step_no = 0
         self.scrub_reports: list[dict] = []
+        # (max_active, 1, V) device logits of the latest decode step, for
+        # callers that check the served numerics (chip_smoke.py)
+        self.last_logits = None
 
     def _default_capacity(self, cfg: ArchConfig, max_active: int) -> int:
         pages_per_seq = -(-self.max_seq // self.pkv.page_tokens)
@@ -731,6 +734,7 @@ class ServingEngine:
             # background scrub's scan/drain dispatches below queue behind
             # the decode step instead of waiting out its completion
             nxt_dev = jnp.argmax(logits[:, -1, :], axis=-1)
+            self.last_logits = logits
         self._step_no += 1
         if self.protected:
             self._touch_pages()

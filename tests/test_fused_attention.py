@@ -146,6 +146,39 @@ def test_fused_pallas_kernel_allclose():
     np.testing.assert_allclose(kern, ref, atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("code_name,page_shape,S", [
+    ("wl160_r08", (1, 16, 2, 64), 3),     # engine layout at head_dim 64
+    ("wl40_r08", (2, 4, 2, 8), 2),        # 2 digit rows per word group
+    ("wl160_r08_gf7", (1, 8, 2, 64), 2),  # GF(7): 3 digits per byte
+])
+def test_pallas_kernel_slot_layout_allclose(rng, code_name, page_shape, S):
+    """Raw operands in the serving engine's per-slot layout (S sub-pages per
+    page step, ragged valid counts incl. empty rows and zero pad pages):
+    the Pallas kernel (interpreted) against the jnp oracle."""
+    from repro.memory.paged import words_for_tensor
+    n, k, p, _ = REGISTRY[code_name]
+    Bsub, T, Hkv, Dh = page_shape
+    B, NP, Hq = S * Bsub, 3, 2 * Hkv
+    W = words_for_tensor(page_shape, p, k)
+    kp, vp = (jnp.asarray(rng.integers(0, p, (NP, S, W, n)), jnp.int32)
+              for _ in range(2))
+    ks, vs = (jnp.asarray(rng.uniform(0.01, 0.05, (NP, S)), jnp.float32)
+              for _ in range(2))
+    valid = rng.integers(0, T + 1, (NP, B))
+    valid[-1] = 0                                # a zero pad page step
+    q = jnp.asarray(rng.normal(size=(B, 1, Hq, Dh)), jnp.bfloat16)
+    hk, hv = (jnp.asarray(rng.normal(size=(B, T, Hkv, Dh)), jnp.bfloat16)
+              for _ in range(2))
+    hval = jnp.asarray(rng.integers(0, T + 1, B), jnp.int32)
+    args = (q, kp, vp, ks, vs, jnp.asarray(valid, jnp.int32), hk, hv, hval)
+    kw = dict(p=p, k_info=k, page_shape=page_shape)
+    ref = ops.attend_protected(*args, policy="ref", **kw)
+    kern = ops.attend_protected(*args, policy="interpret", **kw)
+    np.testing.assert_allclose(np.asarray(kern, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
 def test_fused_off_streams(monkeypatch):
     """fused=False must never touch attend_protected."""
     layer = _mk_layer("wl40_r08", fused=False)

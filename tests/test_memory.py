@@ -118,7 +118,10 @@ def test_write_corrupt_read_roundtrip_exact(policy, rng):
         mem.controller.interval = 10 ** 9            # no auto-sweeps here
     t = rng.normal(size=(24, 12)).astype(np.float32)
     mem.write("t", t)
-    mem.inject(asymmetric_adjacent(3, 3e-3, 1e-3), key=jax.random.PRNGKey(0))
+    # one error in every word: wl80_r08 corrects any single symbol error,
+    # whereas a per-cell channel draw can put 3 errors into one word
+    mem.inject(asymmetric_adjacent(3, 3e-3, 1e-3), key=jax.random.PRNGKey(0),
+               exact=1)
     out = mem.read("t")
     assert np.array_equal(out, t)
     assert out.dtype == t.dtype

@@ -60,11 +60,30 @@ def _budgeted_setup(rng, err_rate):
     return code, We, x, prot, cfgp
 
 
+def _unambiguous_column(code) -> int:
+    """First column whose single-error syndromes e·H[:, j] (e != 0) no other
+    (column, value) pair shares: one error there is always correctable."""
+    H = np.asarray(code.H) % code.p
+    seen: dict = {}
+    for j in range(code.n):
+        for e in range(1, code.p):
+            seen.setdefault(tuple((e * H[:, j]) % code.p), []).append(j)
+    return next(j for j in range(code.n)
+                if all(len(seen[tuple((e * H[:, j]) % code.p)]) == 1
+                       for e in range(1, code.p)))
+
+
 def test_budgeted_overflow_spares_corrected_words(rng):
     """Regression: on budget overflow, words the budget DID correct must not
     be reported uncorrected — only decode failures and the flagged words the
     budget never reached."""
-    code, We, x, prot, cfgp = _budgeted_setup(rng, 0.02)
+    code, We, x, prot, cfgp = _budgeted_setup(rng, 0.0)
+    # placed faults: one weight cell off by one in each codeword block puts
+    # exactly one (correctable) error into every word of each batch row
+    # that reads that weight row — an overflow the budget can correct
+    i = int(np.argmax((np.asarray(x) != 0).sum(axis=0)))
+    j = _unambiguous_column(code)
+    We = We.at[i, j::code.n].add(1)
     res = protected_pim_matmul_budgeted(x, We, code, prot, cfgp,
                                         key=jax.random.PRNGKey(0), budget=2)
     det = np.asarray(res.detected)
@@ -110,8 +129,9 @@ from repro.distributed.sharding import use_rules
 
 cfg = get_config("olmoe_1b_7b").reduced(n_experts=8)
 cfg = dataclasses.replace(cfg, top_k=2, capacity_factor=8.0)
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 key = jax.random.PRNGKey(0)
 params = init_moe(key, cfg, 32)
 rng = np.random.default_rng(0)

@@ -202,8 +202,9 @@ def test_constrain_is_noop_without_mesh():
 
 def test_resolve_spec_with_rules():
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with use_rules(mesh, {"batch": "data", "d_ff": "model", "kv_seq": None}):
         assert resolve_spec(("batch", None, "d_ff")) == P("data", None, "model")
         assert resolve_spec(("kv_seq",)) == P(None)

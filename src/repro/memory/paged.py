@@ -43,6 +43,7 @@ from repro.core.decode import decode_integers
 from repro.core.protected import decode_pipelined, np_prod_mesh
 from repro.obs import metrics as obs_metrics
 from repro.obs import ras as obs_ras
+from repro.obs.trace import span
 
 from .channel import Channel, apply_faults
 from .controller import ControllerStats
@@ -566,15 +567,16 @@ class PagedProtectedStore:
         `coalesce=False` keeps the per-page scan→whole-page-decode baseline
         (bit-identical repairs; FBP is row-independent)."""
         idxs = list(range(self.n_pages) if pages is None else pages)
-        if coalesce:
-            report = self._scrub_coalesced(idxs)
-        else:
-            report = self._scrub_baseline(idxs)
-        self.stats.scrub_rounds += 1
-        self.stats.scrub_words += report["pages"] * self.page_words
-        self.stats.scrub_corrected += report["repaired_words"]
-        self.stats.scrub_uncorrectable += (report["flagged_words"]
-                                           - report["repaired_words"])
+        with span("scrub.sweep"):
+            if coalesce:
+                report = self._scrub_coalesced(idxs)
+            else:
+                report = self._scrub_baseline(idxs)
+            self.stats.scrub_rounds += 1
+            self.stats.scrub_words += report["pages"] * self.page_words
+            self.stats.scrub_corrected += report["repaired_words"]
+            self.stats.scrub_uncorrectable += (report["flagged_words"]
+                                               - report["repaired_words"])
         return report
 
     def _scrub_baseline(self, idxs: list[int]) -> dict:
@@ -602,33 +604,42 @@ class PagedProtectedStore:
         flagged pages whole in a second batched sync, one coalesced
         bucketed drain. Rows are sliced and repaired on host page copies
         so every device op stays page- or bucket-shaped — per-flag-count
-        gathers/scatters would recompile on every new count."""
+        gathers/scatters would recompile on every new count. The phases
+        are `scrub.*` spans whose args count what each moved."""
         if not idxs:
             return {"pages": 0, "flagged_words": 0, "repaired_words": 0,
                     "coalesced": True}
         scan = self._scanner()
-        masks = jax.device_get([scan(self.page(i)) for i in idxs])
+        with span("scrub.scan_dispatch", dispatches=len(idxs)):
+            launched = [scan(self.page(i)) for i in idxs]
+        with span("scrub.mask_pull") as sp:
+            masks = jax.device_get(launched)
+            sp.set(bytes=len(masks) * masks[0].nbytes)
         queue = self._repair_queue()
         owner = getattr(self, "owner", None)
-        flagged_words = 0
-        flagged = [(i, rows) for i, mask in zip(idxs, masks, strict=True)
-                   if (rows := np.flatnonzero(mask)).size]
-        pages = jax.device_get([self.page(i) for i, _ in flagged])
-        for (i, rows), arr in zip(flagged, pages, strict=True):
-            arr = np.array(arr)        # device_get views can be read-only
-            flagged_words += int(rows.size)
+        with span("scrub.page_pull") as sp:
+            flagged = [(i, rows) for i, mask in zip(idxs, masks, strict=True)
+                       if (rows := np.flatnonzero(mask)).size]
+            pages = jax.device_get([self.page(i) for i, _ in flagged])
+            flagged_words = 0
+            for (i, rows), arr in zip(flagged, pages, strict=True):
+                arr = np.array(arr)    # device_get views can be read-only
+                flagged_words += int(rows.size)
 
-            def writeback(syms, ok, i=i, rows=rows, arr=arr):
-                good = rows[ok]
-                if good.size:
+                def writeback(syms, ok, i=i, rows=rows, arr=arr):
+                    good = rows[ok]
+                    if not good.size:
+                        return 0
                     arr[good] = syms[ok].astype(arr.dtype)
                     self._set_page(i, jnp.asarray(arr, jnp.int32))
+                    return arr.nbytes
 
-            queue.enqueue(arr[rows], writeback, owner=owner,
-                          provenance=("store", i, rows))
+                queue.enqueue(arr[rows], writeback, owner=owner,
+                              provenance=("store", i, rows))
+            sp.set(bytes=len(pages) * self.page_words * self.code.n * 4)
         rep = queue.drain()
         return {"pages": len(idxs), "flagged_words": flagged_words,
                 "repaired_words": rep["repaired"], "coalesced": True,
                 "drain": {k: rep[k] for k in (
                     "entries", "words", "repaired", "failed", "pad_rows",
-                    "dispatch_rows", "pad_waste", "seconds")}}
+                    "dispatch_rows", "pad_waste")}}

@@ -38,6 +38,7 @@ import numpy as np
 from repro.core.construction import LDPCCode
 from repro.obs import metrics as obs_metrics
 from repro.obs import ras as obs_ras
+from repro.obs.trace import span
 
 from .channel import apply_faults
 from .controller import ControllerStats
@@ -232,12 +233,13 @@ class ProtectedPagePool:
                 self._scrub_cursor = pid + 1
         if self._scrub_cursor >= self.capacity_pages:
             self._scrub_cursor = 0
-        if coalesce:
-            swept, flagged_words, repaired, by_owner = \
-                self._scrub_selected_coalesced(selected)
-        else:
-            swept, flagged_words, repaired, by_owner = \
-                self._scrub_selected_baseline(selected)
+        with span("scrub.sweep"):
+            if coalesce:
+                swept, flagged_words, repaired, by_owner = \
+                    self._scrub_selected_coalesced(selected)
+            else:
+                swept, flagged_words, repaired, by_owner = \
+                    self._scrub_selected_baseline(selected)
         self.stats.scrub_rounds += 1
         self.stats.scrub_words += swept * self.page_words
         self.stats.scrub_corrected += repaired
@@ -318,35 +320,43 @@ class ProtectedPagePool:
         writes happen on host page copies: every device op here is
         page-shaped or bucket-shaped, so sweeps reuse warm executables no
         matter how the flag counts vary (a per-flag-count gather/scatter
-        would recompile on every new count)."""
+        would recompile on every new count). The phases are the same
+        `scrub.*` spans as the paged store's."""
         if not selected:
             return 0, 0, 0, {}
         scan = self._template._scanner()
-        masks = jax.device_get(
-            [scan(self._storage[pid]) for pid in selected])
+        with span("scrub.scan_dispatch", dispatches=len(selected)):
+            launched = [scan(self._storage[pid]) for pid in selected]
+        with span("scrub.mask_pull") as sp:
+            masks = jax.device_get(launched)
+            sp.set(bytes=len(masks) * masks[0].nbytes)
         est = obs_ras.current()
         queue = self._template._repair_queue()
-        flagged_words = 0
-        flagged = []
-        for pid, mask in zip(selected, masks, strict=True):
-            rows = np.flatnonzero(mask)
-            owner = self._note_page_scan(pid, int(rows.size), est)
-            if rows.size:
-                flagged.append((pid, rows, owner))
-                flagged_words += int(rows.size)
-        pages = jax.device_get([self._storage[pid]
-                                for pid, _, _ in flagged])
-        for (pid, rows, owner), arr in zip(flagged, pages, strict=True):
-            arr = np.array(arr)        # device_get views can be read-only
+        with span("scrub.page_pull") as sp:
+            flagged_words = 0
+            flagged = []
+            for pid, mask in zip(selected, masks, strict=True):
+                rows = np.flatnonzero(mask)
+                owner = self._note_page_scan(pid, int(rows.size), est)
+                if rows.size:
+                    flagged.append((pid, rows, owner))
+                    flagged_words += int(rows.size)
+            pages = jax.device_get([self._storage[pid]
+                                    for pid, _, _ in flagged])
+            for (pid, rows, owner), arr in zip(flagged, pages, strict=True):
+                arr = np.array(arr)    # device_get views can be read-only
 
-            def writeback(syms, ok, pid=pid, rows=rows, arr=arr):
-                good = rows[ok]
-                if good.size:
+                def writeback(syms, ok, pid=pid, rows=rows, arr=arr):
+                    good = rows[ok]
+                    if not good.size:
+                        return 0
                     arr[good] = syms[ok].astype(arr.dtype)
                     self._storage[pid] = jnp.asarray(arr, jnp.int32)
+                    return arr.nbytes
 
-            queue.enqueue(arr[rows], writeback, owner=owner,
-                          provenance=("pool", pid, rows))
+                queue.enqueue(arr[rows], writeback, owner=owner,
+                              provenance=("pool", pid, rows))
+            sp.set(bytes=len(pages) * self.page_words * self.code.n * 4)
         rep = queue.drain()
         by_owner = {owner: dict(ent)
                     for owner, ent in rep["by_owner"].items()}

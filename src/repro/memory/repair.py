@@ -50,6 +50,7 @@ from repro.core.decode import decode_integers
 from repro.kernels.ops import np_bucket
 from repro.obs import metrics as obs_metrics
 from repro.obs import ras as obs_ras
+from repro.obs.trace import span
 
 __all__ = ["RepairQueue", "bucket_sizes"]
 
@@ -80,7 +81,8 @@ class _Entry:
     """One enqueued batch of flagged rows awaiting the next drain."""
 
     words: object               # (rows, n) flagged level-words (np or jnp)
-    writeback: Callable         # (symbols (rows, n) int64, ok (rows,)) -> None
+    writeback: Callable         # (symbols (rows, n) int64, ok (rows,))
+                                # -> bytes written back to the device, or None
     owner: object               # tenant label for per-owner attribution
     provenance: tuple           # e.g. ("pool", page_id, row_indices)
     rows: int
@@ -190,18 +192,24 @@ class RepairQueue:
                     np.zeros(0, bool), None, 0)
         cs = self.chunk_size
         launched = []
-        pad_rows = 0
-        for lo in range(0, B, cs):
-            chunk = words[lo:lo + cs]
-            b = int(chunk.shape[0])
-            size = self._dispatch_size(b)
-            pad_rows += size - b
-            _y, res = self._decoder(size)(jnp.asarray(self._pad(chunk, size)))
-            launched.append((res, b))
-        # the drain's single sync: every bucket decode is already in flight
-        pulled = jax.device_get(
-            [(r.symbols, r.detect_fail, getattr(r, "iterations", None))
-             for r, _ in launched])
+        pad_rows = nbytes = 0
+        with span("repair.decode") as sp:
+            for lo in range(0, B, cs):
+                chunk = words[lo:lo + cs]
+                b = int(chunk.shape[0])
+                size = self._dispatch_size(b)
+                pad_rows += size - b
+                padded = self._pad(chunk, size)
+                nbytes += padded.nbytes
+                _y, res = self._decoder(size)(jnp.asarray(padded))
+                launched.append((res, b))
+            # the drain's single sync: every bucket decode is in flight
+            pulled = jax.device_get(
+                [(r.symbols, r.detect_fail, getattr(r, "iterations", None))
+                 for r, _ in launched])
+            nbytes += sum(a.nbytes for t in pulled for a in t
+                          if a is not None)
+            sp.set(dispatches=len(launched), bytes=nbytes)
         syms = np.empty((B, self.code.n), np.int64)
         fail = np.empty(B, bool)
         have_iters = all(t[2] is not None for t in pulled)
@@ -230,8 +238,10 @@ class RepairQueue:
                 provenance: tuple = ()) -> None:
         """Queue (rows, n) flagged level-words for the next drain.
         `writeback(symbols, ok)` is called with the decoded (rows, n) int64
-        symbols and the (rows,) repaired mask; `owner` labels the rows for
-        per-tenant attribution in the drain report."""
+        symbols and the (rows,) repaired mask, and may return the bytes it
+        wrote back to the device (counted on the `repair.writeback` span);
+        `owner` labels the rows for per-tenant attribution in the drain
+        report."""
         rows = int(words.shape[0])
         if rows == 0:
             return
@@ -260,22 +270,24 @@ class RepairQueue:
         syms, fail, iters, pad_rows = self.decode_batch(batch)
         est = obs_ras.current()
         by_owner: dict[object, dict] = {}
-        lo = 0
-        for e in entries:
-            s = syms[lo:lo + e.rows]
-            f = fail[lo:lo + e.rows]
-            ok = ~f
-            e.writeback(s, ok)
-            ent = by_owner.setdefault(
-                e.owner, {"flagged_words": 0, "repaired_words": 0})
-            ent["flagged_words"] += e.rows
-            ent["repaired_words"] += int(ok.sum())
-            if est.enabled and iters is not None:
-                est.observe_decode(iters[lo:lo + e.rows], self.n_iters,
-                                   detect_fail=f,
-                                   region=str(e.owner)
-                                   if e.owner is not None else "")
-            lo += e.rows
+        with span("repair.writeback") as sp:
+            lo = nbytes = 0
+            for e in entries:
+                s = syms[lo:lo + e.rows]
+                f = fail[lo:lo + e.rows]
+                ok = ~f
+                nbytes += e.writeback(s, ok) or 0
+                ent = by_owner.setdefault(
+                    e.owner, {"flagged_words": 0, "repaired_words": 0})
+                ent["flagged_words"] += e.rows
+                ent["repaired_words"] += int(ok.sum())
+                if est.enabled and iters is not None:
+                    est.observe_decode(iters[lo:lo + e.rows], self.n_iters,
+                                       detect_fail=f,
+                                       region=str(e.owner)
+                                       if e.owner is not None else "")
+                lo += e.rows
+            sp.set(bytes=nbytes)
         dt = time.perf_counter() - t0
         repaired = int((~fail).sum())
         failed = pending - repaired

@@ -28,12 +28,15 @@ Two jax-aware extras:
   `jax.profiler.trace(...)` capture when one is active.
 
 Nesting is tracked per thread: sibling and child spans nest correctly in
-the rendered flame because their timestamps nest; `depth` rides in the
-event args for programmatic consumers (tests assert ordering with it).
+the rendered flame because their timestamps nest. Each span's event args
+carry its integer `id`, the `parent` id of the span that enclosed it on
+the same thread (None at the top) and its `depth`, so one call's spans
+form a tree and a layer's self time is its duration less its children's.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import threading
 import time
@@ -44,7 +47,8 @@ __all__ = ["Tracer", "NULL_TRACER", "current", "use_tracer", "span"]
 class _Span:
     """One in-flight span (context manager recorded on exit)."""
 
-    __slots__ = ("tracer", "name", "args", "sync", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "sync", "t0", "depth", "id",
+                 "parent")
 
     def __init__(self, tracer: "Tracer", name: str, sync, args: dict):
         self.tracer = tracer
@@ -53,12 +57,17 @@ class _Span:
         self.sync = sync
         self.t0 = 0
         self.depth = 0
+        self.id = 0
+        self.parent = None
 
     def __enter__(self):
+        self.tracer._enter_profiler(self.name)
         tl = self.tracer._tls
         self.depth = getattr(tl, "depth", 0)
+        self.parent = getattr(tl, "span_id", None)
+        self.id = next(self.tracer._ids)
         tl.depth = self.depth + 1
-        self.tracer._enter_profiler(self.name)
+        tl.span_id = self.id
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -68,8 +77,10 @@ class _Span:
             jax.block_until_ready(self.sync)
         t1 = time.perf_counter_ns()
         self.tracer._exit_profiler()
-        self.tracer._tls.depth = self.depth
-        self.tracer._record(self.name, self.t0, t1, self.depth, self.args)
+        tl = self.tracer._tls
+        tl.depth = self.depth
+        tl.span_id = self.parent
+        self.tracer._record(self, t1)
         return False
 
     def set(self, **args) -> None:
@@ -126,6 +137,7 @@ class Tracer:
         self._epoch_ns = time.perf_counter_ns()
         self._tls = threading.local()
         self._lock = threading.Lock()
+        self._ids = itertools.count()
 
     # -- recording -----------------------------------------------------------
 
@@ -142,13 +154,13 @@ class Tracer:
                       "pid": self.pid, "tid": threading.get_ident() % 2**31,
                       "args": args})
 
-    def _record(self, name, t0_ns, t1_ns, depth, args) -> None:
-        ev_args = dict(args)
-        ev_args["depth"] = depth
+    def _record(self, sp: _Span, t1_ns: int) -> None:
+        ev_args = dict(sp.args)
+        ev_args.update(depth=sp.depth, id=sp.id, parent=sp.parent)
         self._append({
-            "name": name, "ph": "X",
-            "ts": (t0_ns - self._epoch_ns) / 1e3,        # microseconds
-            "dur": (t1_ns - t0_ns) / 1e3,
+            "name": sp.name, "ph": "X",
+            "ts": (sp.t0 - self._epoch_ns) / 1e3,        # microseconds
+            "dur": (t1_ns - sp.t0) / 1e3,
             "pid": self.pid, "tid": threading.get_ident() % 2**31,
             "args": ev_args})
 
@@ -160,18 +172,17 @@ class Tracer:
             self._events.append(ev)
 
     def _enter_profiler(self, name: str) -> None:
+        """Open the span's `TraceAnnotation`. A failure propagates: a
+        traced run that cannot annotate must fail, not lose its spans."""
         if not self.jax_profiler:
             return
-        try:
-            import jax.profiler
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-            stack = getattr(self._tls, "annotations", None)
-            if stack is None:
-                stack = self._tls.annotations = []
-            stack.append(ann)
-        except Exception:
-            self.jax_profiler = False       # bridge unavailable: degrade
+        import jax.profiler
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        stack = getattr(self._tls, "annotations", None)
+        if stack is None:
+            stack = self._tls.annotations = []
+        stack.append(ann)
 
     def _exit_profiler(self) -> None:
         if not self.jax_profiler:

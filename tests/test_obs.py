@@ -100,13 +100,59 @@ def test_span_nesting_and_chrome_trace_export(tmp_path):
 
     inner, outer = tr.spans("inner")[0], tr.spans("outer")[0]
     # children close (and therefore record) before their parents; the
-    # timestamps nest and depth rides in args
+    # timestamps nest and depth, id and parent ride in args
     assert outer["args"]["depth"] == 0 and inner["args"]["depth"] == 1
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
-    assert outer["args"] == {"step": 1, "tokens": 4, "depth": 0}
+    assert outer["args"] == {"step": 1, "tokens": 4, "depth": 0,
+                             "id": outer["args"]["id"], "parent": None}
+    assert inner["args"]["parent"] == outer["args"]["id"]
     marks = [e for e in tr.events() if e["ph"] == "i"]
     assert marks and marks[0]["name"] == "mark"
+
+
+def test_span_ids_link_children_to_parents_across_siblings():
+    tr = obs.Tracer()
+    with obs.use_tracer(tr):
+        with obs.span("root"):
+            with obs.span("a"):
+                with obs.span("a1"):
+                    pass
+            with obs.span("b"):
+                pass
+        with obs.span("second_root"):
+            pass
+    ev = {e["name"]: e["args"] for e in tr.spans()}
+    ids = [a["id"] for a in ev.values()]
+    assert len(set(ids)) == len(ids) and all(isinstance(i, int) for i in ids)
+    assert ev["root"]["parent"] is None
+    assert ev["second_root"]["parent"] is None
+    assert ev["a"]["parent"] == ev["b"]["parent"] == ev["root"]["id"]
+    assert ev["a1"]["parent"] == ev["a"]["id"]
+    assert [ev[n]["depth"] for n in ("root", "a", "a1", "b", "second_root")
+            ] == [0, 1, 2, 1, 0]
+
+
+def test_profiler_bridge_failure_propagates(monkeypatch):
+    """A tracer that mirrors into the profiler and cannot annotate fails
+    the span instead of quietly recording without annotations."""
+    import jax.profiler
+
+    class Broken:
+        def __init__(self, name):
+            raise RuntimeError("no annotations here")
+
+    tr = obs.Tracer(jax_profiler=True)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Broken)
+    with pytest.raises(RuntimeError, match="no annotations"):
+        with tr.span("x"):
+            pass
+    assert tr.jax_profiler and not tr.spans()
+    monkeypatch.undo()
+    with tr.span("y"):                  # the failed span left no state
+        pass
+    assert tr.spans("y")[0]["args"]["depth"] == 0
+    assert tr.spans("y")[0]["args"]["parent"] is None
 
 
 def test_tracer_bounds_event_count():

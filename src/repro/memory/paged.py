@@ -10,8 +10,9 @@ workloads:
   `repro.kernels.ops.encode_words` (the Pallas `gf_matmul` MXU path with the
   mod-p fused epilogue); one cached (page_words, k) executable serves every
   append, and pages never round-trip through the host;
-- **scan on device** — per-page syndrome flagging via the fused
-  `scan_syndromes` kernel (only the (page_words,) mask leaves the device);
+- **scan on device** — syndrome flagging via the fused `scan_syndromes`
+  kernel (only the (page_words,) masks leave the device); a scrub scans a
+  group of up to `SCAN_GROUP` pages per launch;
 - **streaming corrected reads** — `iter_corrected()` walks the pages through
   `repro.core.protected.decode_pipelined`: page *i+1*'s decode is dispatched
   before page *i* is yielded, so decode latency hides behind the consumer
@@ -51,6 +52,10 @@ from .packing import digits_per_byte, symbolize_u8, desymbolize_u8
 
 __all__ = ["PagedProtectedStore", "QuantizedTensor", "quantize_tensor",
            "dequantize_tensor", "words_for_tensor"]
+
+# Pages a coalesced scrub scans per launch, a power of two: 256 pages of 256
+# words is 64 Ki words, a 64 MiB int8 operand for the fused scan.
+SCAN_GROUP = 256
 
 
 # ---------------------------------------------------------------------------
@@ -177,6 +182,7 @@ class PagedProtectedStore:
         self._injections = 0
         self._encode_fn = None
         self._scan_fn = None
+        self._group_scan_fns: dict = {}
         self._decode_fn = None
         self._repair_q = None
         # read/scrub correction accounting (per-store, so a serving layer can
@@ -255,6 +261,19 @@ class PagedProtectedStore:
                     lambda u: encode_words_ref(u, P, p))
         return self._encode_fn
 
+    def _row_scan(self):
+        """(rows, n) -> (rows,) flags, unjitted: the fused Pallas scan, or
+        its jnp oracle under the `ref` policy (mode resolved now)."""
+        ht = jnp.asarray(self.code.H.T, jnp.int32)
+        p = self.code.p
+        mode = self._mode()
+        if mode != "ref":
+            from repro.kernels.ops import scan_syndromes
+            interp = mode == "interpret"
+            return lambda y: scan_syndromes(y, ht, p, interpret=interp)
+        from repro.kernels.ref import scan_syndromes_ref
+        return lambda y: scan_syndromes_ref(y, ht, p)
+
     def _scanner(self):
         """One cached (page_words, n) syndrome-scan executable (fused Pallas
         kernel on TPU, jnp oracle elsewhere; sharded over `mesh` when
@@ -266,19 +285,20 @@ class PagedProtectedStore:
                 self._scan_fn = jax.jit(
                     lambda y: scan_syndromes_sharded(code, y, mesh=mesh))
             else:
-                ht = jnp.asarray(self.code.H.T, jnp.int32)
-                p = self.code.p
-                mode = self._mode()
-                if mode != "ref":
-                    from repro.kernels.ops import scan_syndromes
-                    interp = mode == "interpret"
-                    self._scan_fn = jax.jit(
-                        lambda y: scan_syndromes(y, ht, p, interpret=interp))
-                else:
-                    from repro.kernels.ref import scan_syndromes_ref
-                    self._scan_fn = jax.jit(
-                        lambda y: scan_syndromes_ref(y, ht, p))
+                self._scan_fn = jax.jit(self._row_scan())
         return self._scan_fn
+
+    def _group_scanner(self, g: int):
+        """One cached executable per group size `g`: a tuple of `g`
+        (page_words, n) pages -> (g, page_words) bool masks, from one scan
+        of the pages stacked row-wise (the kernel's int8 cast fuses with
+        the concatenate)."""
+        fn = self._group_scan_fns.get(g)
+        if fn is None:
+            scan = self._row_scan()
+            fn = self._group_scan_fns[g] = jax.jit(
+                lambda pages: scan(jnp.concatenate(pages)).reshape(g, -1))
+        return fn
 
     def _decoder(self):
         """One cached (page_words, n) decode executable (sharded over
@@ -559,8 +579,9 @@ class PagedProtectedStore:
         page indices (the engine's cold-page background scrub). Returns
         {pages, flagged_words, repaired_words}.
 
-        `coalesce=True` (default) runs the repair pipeline: every page's
-        scan is dispatched before any mask is pulled (one sync for the
+        `coalesce=True` (default) runs the repair pipeline: the pages are
+        scanned a group at a time (`SCAN_GROUP` pages per launch), every
+        group is launched before any mask is pulled (one sync for the
         sweep), flagged rows are gathered on device and coalesced across
         pages on the `RepairQueue`, and one bucketed drain repairs them —
         sparse flags pay a bucket-sized FBP instead of a whole-page one.
@@ -599,22 +620,44 @@ class PagedProtectedStore:
         return {"pages": swept, "flagged_words": flagged_words,
                 "repaired_words": repaired, "coalesced": False}
 
+    def _scan_masks(self, pages: list) -> np.ndarray:
+        """(len(pages), page_words) bool scan masks of `pages`: every
+        group's scan is launched before one `device_get` pulls every mask.
+        A group holds `SCAN_GROUP` pages; the last one is padded up to a
+        power of two with repeats of its first page, whose masks are
+        dropped, so sweeps build at most log2(SCAN_GROUP) + 1 shapes
+        whatever their page counts. A `mesh` store launches its sharded
+        scan page by page."""
+        m, pw = len(pages), self.page_words
+        g_max = 1 if self.mesh is not None else SCAN_GROUP
+        groups = [pages[lo:lo + g_max] for lo in range(0, m, g_max)]
+        with span("scrub.scan_dispatch", dispatches=len(groups), pages=m):
+            if self.mesh is not None:
+                scan = self._scanner()
+                launched = [scan(grp[0]) for grp in groups]
+            else:
+                launched = []
+                for grp in groups:
+                    g = 1 << (len(grp) - 1).bit_length()
+                    launched.append(self._group_scanner(g)(
+                        tuple(grp + grp[:1] * (g - len(grp)))))
+        with span("scrub.mask_pull") as sp:
+            pulled = jax.device_get(launched)
+            sp.set(bytes=sum(a.nbytes for a in launched))
+        return np.concatenate([a.reshape(-1, pw) for a in pulled])[:m]
+
     def _scrub_coalesced(self, idxs: list[int]) -> dict:
-        """Pipelined sweep: dispatch all scans, one mask sync, pull the
-        flagged pages whole in a second batched sync, one coalesced
-        bucketed drain. Rows are sliced and repaired on host page copies
-        so every device op stays page- or bucket-shaped — per-flag-count
-        gathers/scatters would recompile on every new count. The phases
-        are `scrub.*` spans whose args count what each moved."""
+        """Pipelined sweep: launch the scans a group of pages at a time,
+        one mask sync, pull the flagged pages whole in a second batched
+        sync, one coalesced bucketed drain. Rows are sliced and repaired
+        on host page copies so every device op stays group-, page- or
+        bucket-shaped — per-flag-count gathers/scatters would recompile on
+        every new count. The phases are `scrub.*` spans whose args count
+        what each moved."""
         if not idxs:
             return {"pages": 0, "flagged_words": 0, "repaired_words": 0,
                     "coalesced": True}
-        scan = self._scanner()
-        with span("scrub.scan_dispatch", dispatches=len(idxs)):
-            launched = [scan(self.page(i)) for i in idxs]
-        with span("scrub.mask_pull") as sp:
-            masks = jax.device_get(launched)
-            sp.set(bytes=len(masks) * masks[0].nbytes)
+        masks = self._scan_masks([self.page(i) for i in idxs])
         queue = self._repair_queue()
         owner = getattr(self, "owner", None)
         with span("scrub.page_pull") as sp:

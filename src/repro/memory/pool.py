@@ -200,11 +200,12 @@ class ProtectedPagePool:
         been flagging (the estimator-driven schedule the serving engine
         uses) instead of whatever the cursor reaches next.
 
-        `coalesce=True` (default) runs the repair pipeline: every in-budget
-        page's scan is dispatched before any mask is pulled (one sync per
-        sweep), and all tenants' flagged rows coalesce through the shared
-        `RepairQueue` into one bucketed drain — the multi-tenant engine's
-        background scrub amortizes one drain per step. `coalesce=False`
+        `coalesce=True` (default) runs the repair pipeline: the in-budget
+        pages are scanned a group at a time, every group launched before
+        any mask is pulled (one sync per sweep), and all tenants' flagged
+        rows coalesce through the shared `RepairQueue` into one bucketed
+        drain — the multi-tenant engine's background scrub amortizes one
+        drain per step. `coalesce=False`
         keeps the per-page scan→whole-page-decode baseline (bit-identical
         repairs and identical per-owner attribution)."""
         allocated = [pid for pid in range(self.capacity_pages)
@@ -312,7 +313,8 @@ class ProtectedPagePool:
         return len(selected), flagged_words, repaired, by_owner
 
     def _scrub_selected_coalesced(self, selected: list[int]):
-        """Pipelined sweep over the selected pids: every scan dispatched
+        """Pipelined sweep over the selected pids: the scans launched a
+        group of pages at a time (`PagedProtectedStore._scan_masks`)
         before one mask sync, flagged pages pulled whole in a second
         batched sync, flagged rows from every tenant's pages coalesced
         through the shared `RepairQueue`, one bucketed drain (which also
@@ -324,12 +326,8 @@ class ProtectedPagePool:
         `scrub.*` spans as the paged store's."""
         if not selected:
             return 0, 0, 0, {}
-        scan = self._template._scanner()
-        with span("scrub.scan_dispatch", dispatches=len(selected)):
-            launched = [scan(self._storage[pid]) for pid in selected]
-        with span("scrub.mask_pull") as sp:
-            masks = jax.device_get(launched)
-            sp.set(bytes=len(masks) * masks[0].nbytes)
+        masks = self._template._scan_masks(
+            [self._storage[pid] for pid in selected])
         est = obs_ras.current()
         queue = self._template._repair_queue()
         with span("scrub.page_pull") as sp:
@@ -503,6 +501,9 @@ class PooledStore(PagedProtectedStore):
 
     def _scanner(self):
         return self.pool._template._scanner()
+
+    def _group_scanner(self, g: int):
+        return self.pool._template._group_scanner(g)
 
     def _decoder(self):
         return self.pool._template._decoder()

@@ -3,8 +3,9 @@
 A sweep of `PagedProtectedStore` (and of `ProtectedPagePool`) records one
 `scrub.sweep` span whose children name each phase: the scan dispatch, the
 mask pull, the flagged-page pull, and the repair queue's decode and
-writeback. Their args count what each phase moved, computed
-from shapes the host already holds, so tracing adds no host sync.
+writeback. Their args count what each phase moved (the scan dispatch:
+the group launches and the pages they cover), computed from shapes the
+host already holds, so tracing adds no host sync.
 """
 import jax
 import numpy as np
@@ -13,10 +14,12 @@ import pytest
 from repro import obs
 from repro.core import get_code, np_encode_words
 from repro.memory import PagedProtectedStore, PooledStore, ProtectedPagePool
+from repro.memory import paged
 from repro.obs import trace as obs_trace
 
 PW = 16                 # words a page
 PAGES = 6
+G = 4                   # SCAN_GROUP here: one full group, a tail of 2
 CHILDREN = ("scrub.scan_dispatch", "scrub.mask_pull", "scrub.page_pull",
             "repair.decode", "repair.writeback")
 
@@ -24,6 +27,11 @@ CHILDREN = ("scrub.scan_dispatch", "scrub.mask_pull", "scrub.page_pull",
 @pytest.fixture(scope="module")
 def code():
     return get_code("wl160_r08")
+
+
+@pytest.fixture
+def small_groups(monkeypatch):
+    monkeypatch.setattr(paged, "SCAN_GROUP", G)
 
 
 def _damaged(code, rows):
@@ -46,7 +54,7 @@ def _expected(code, rows):
     n, flagged_pages = code.n, len(set(int(r) // PW for r in rows))
     buckets = -(-len(rows) // PW)      # the store's queue: one PW-row bucket
     decode_bytes = buckets * PW * (n * 4 + n * 4 + 1 + 4)
-    return {"scrub.scan_dispatch": {"dispatches": PAGES},
+    return {"scrub.scan_dispatch": {"dispatches": 2, "pages": PAGES},
             "scrub.mask_pull": {"bytes": PAGES * PW},
             "scrub.page_pull": {"bytes": flagged_pages * PW * n * 4},
             "repair.decode": {"dispatches": buckets, "bytes": decode_bytes},
@@ -70,7 +78,7 @@ def _check_tree(tr, want):
         assert got == args, name
 
 
-def test_store_sweep_records_one_tree_with_counts(code):
+def test_store_sweep_records_one_tree_with_counts(code, small_groups):
     bad, clean = _damaged(code, ROWS)
     st = PagedProtectedStore(code, page_words=PW)
     st.append_encoded(bad)
@@ -82,7 +90,7 @@ def test_store_sweep_records_one_tree_with_counts(code):
     np.testing.assert_array_equal(st.export_words(), clean)
 
 
-def test_pool_sweep_emits_the_same_spans(code):
+def test_pool_sweep_emits_the_same_spans(code, small_groups):
     bad, clean = _damaged(code, ROWS)
     pool = ProtectedPagePool(code, page_words=PW, capacity_pages=PAGES + 2)
     a, b = PooledStore(pool, owner="a"), PooledStore(pool, owner="b")
